@@ -82,6 +82,8 @@ def main() -> None:
                          "MEMPROF_<suite>.json, and (with --profile) add "
                          "Perfetto counter tracks to TRACE_<suite>.json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.profile or args.memprof:
         from repro.obs import metrics as obs_metrics
         from repro.obs import slo as obs_slo

@@ -38,13 +38,8 @@ def encode_lines(data: jax.Array) -> jax.Array:
         raise ValueError(f"last dim must be a multiple of 16, got {data.shape}")
     lines = data.reshape(*data.shape[:-1], data.shape[-1] // WORDS_PER_LINE,
                          WORDS_PER_LINE)
-    folded = jax.lax.reduce_xor(
-        lines.astype(jnp.uint32), axes=(lines.ndim - 1,)
-    ) if hasattr(jax.lax, "reduce_xor") else None
-    if folded is None:  # pragma: no cover - fallback for older jax
-        folded = lines[..., 0]
-        for i in range(1, WORDS_PER_LINE):
-            folded = folded ^ lines[..., i]
+    folded = jax.lax.reduce_xor(lines.astype(jnp.uint32),
+                                axes=(lines.ndim - 1,))
     return _fold_byte(folded)
 
 

@@ -2,7 +2,6 @@
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 
@@ -17,13 +16,9 @@ def round_up(a: int, b: int) -> int:
 
 @functools.cache
 def use_interpret() -> bool:
-    """Pallas kernels run in interpret mode off-TPU (this container is CPU).
-
-    On TPU the kernels lower natively; ``REPRO_FORCE_INTERPRET=1`` forces
-    interpret mode for debugging on hardware.
-    """
-    if os.environ.get("REPRO_FORCE_INTERPRET") == "1":
-        return True
+    """Pallas kernels lower natively on TPU and run in interpret mode on
+    the CPU backend (tests, examples). There is no switch: a check that must
+    prove the chip ran (``chip_smoke.py``) asserts this is False."""
     return jax.default_backend() != "tpu"
 
 

@@ -22,8 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.common import pick_block, use_interpret
-from repro.kernels.secded.kernel import (_encode_beats, _syndrome_action,
-                                         _unpack4)
+from repro.kernels.secded.kernel import decode_correct_block
 
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 256, 256, 512
 
@@ -31,16 +30,7 @@ DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 256, 256, 512
 def _decode_tile(bits: jax.Array, packed_codes: jax.Array) -> jax.Array:
     """(BM, BK/2) uint32 + (BM, BK/16) codes -> corrected bf16 (BM, BK)."""
     bm, kw = bits.shape
-    pairs = bits.reshape(bm, kw // 2, 2)
-    lo, hi = pairs[..., 0], pairs[..., 1]
-    stored = _unpack4(packed_codes, lo.shape[1])
-    syndrome = (_encode_beats(lo, hi) ^ stored) & jnp.uint32(0xFF)
-    action = _syndrome_action(syndrome)
-    is_data = (action >= 0) & (action < 64)
-    bit = jnp.where(action >= 0, action, 0).astype(jnp.uint32)
-    lo = lo ^ jnp.where(is_data & (bit < 32), jnp.uint32(1) << (bit & 31), 0)
-    hi = hi ^ jnp.where(is_data & (bit >= 32), jnp.uint32(1) << (bit & 31), 0)
-    fixed = jnp.stack([lo, hi], axis=-1).reshape(bm, kw)
+    fixed = decode_correct_block(bits, packed_codes)
     halves = jax.lax.bitcast_convert_type(fixed, jnp.bfloat16)  # (BM, kw, 2)
     return halves.reshape(bm, kw * 2)
 

@@ -33,10 +33,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.layouts import (CODE_LANE, DATA_LANES, Layout,
-                                extra_base_row)
+from repro.core.layouts import DATA_LANES, LANES, Layout, extra_base_row
 from repro.kernels.common import use_interpret
-from repro.kernels.mixed.kernel import _coords
+from repro.kernels.mixed.kernel import _coords, out_shape, pool_views
 from repro.kernels.secded.kernel import decode_correct_block
 from repro.objcache.hash_index import hash_u32
 
@@ -65,9 +64,9 @@ def _make_body(capacity: int, probe: int, num_rows: int, boundary: int):
         i = pl.program_id(0)
         page, _ = _probe_page(q_ref[i], keys_ref, pages_ref, capacity, probe)
         is_sec = (page >= boundary) & (page < num_rows)
-        blk = storage_ref[...]                            # (1, 1, W)
-        fixed = decode_correct_block(blk, codes_ref[...])
-        out_ref[...] = jnp.where(is_sec, fixed, blk)
+        blk = storage_ref[0]                              # (1, W)
+        fixed = decode_correct_block(blk, codes_ref[0])
+        out_ref[0] = jnp.where(is_sec, fixed, blk)
     return body
 
 
@@ -85,24 +84,26 @@ def lookup_read(storage: jax.Array, slot_keys: jax.Array,
     def storage_index(i, k, q_ref, keys_ref, pages_ref):
         page, _ = _probe_page(q_ref[i], keys_ref, pages_ref, capacity, probe)
         row, lane = _coords(page, k, layout, num_rows, boundary, ebase)
-        return row, lane, 0
+        return row * LANES + lane, 0, 0
 
     def codes_index(i, k, q_ref, keys_ref, pages_ref):
         page, _ = _probe_page(q_ref[i], keys_ref, pages_ref, capacity, probe)
-        return jnp.clip(page, 0, num_rows - 1), CODE_LANE, k
+        return jnp.clip(page, 0, num_rows - 1) * DATA_LANES + k, 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n, DATA_LANES),
         in_specs=[pl.BlockSpec((1, 1, w), storage_index),
                   pl.BlockSpec((1, 1, w // 8), codes_index)],
-        out_specs=pl.BlockSpec((1, 1, w), lambda i, k, q, ks, ps: (i, k, 0)),
+        out_specs=pl.BlockSpec(
+            (1, 1, w), lambda i, k, q, ks, ps: (i * DATA_LANES + k, 0, 0)),
     )
     out = pl.pallas_call(
         _make_body(capacity, probe, num_rows, boundary),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, DATA_LANES, w), jnp.uint32),
+        out_shape=out_shape((n * DATA_LANES, 1, w), storage, slot_keys,
+                            slot_pages, queries),
         interpret=use_interpret(),
     )(queries.astype(jnp.uint32), slot_keys.astype(jnp.uint32),
-      slot_pages.astype(jnp.int32), storage, storage)
+      slot_pages.astype(jnp.int32), *pool_views(storage))
     return out.reshape(n, DATA_LANES * w)

@@ -9,7 +9,9 @@ stream each page HBM→VMEM→HBM→VMEM; this kernel fuses them so every slice 
 touched once:
 
   * grid = (n_pages, 8 slices), page ids scalar-prefetched (the same
-    paged-attention pattern as ``repro.kernels.interwrap``);
+    paged-attention pattern as ``repro.kernels.interwrap``), slices
+    streamed from the Mosaic-legal ``(R·9, 1, W)`` view of
+    :func:`repro.kernels.mixed.kernel.pool_views`;
   * the storage BlockSpec index map performs the paper's §4.1.3 translation
     ℓ = 8·slot + k, lane = ℓ mod 9, row = 8·group + ℓ div 9;
   * the code output is computed per slice: with W % 8 == 0 each W-word slice
@@ -28,9 +30,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.layouts import GROUP_ROWS, LANES
+from repro.core.layouts import DATA_LANES, GROUP_ROWS, LANES
 from repro.kernels.common import use_interpret
-from repro.kernels.secded.kernel import _encode_beats
+from repro.kernels.mixed.kernel import out_shape, pool_views
+from repro.kernels.secded.kernel import _encode_lanes
 
 
 def _coords(page, k, num_rows: int):
@@ -44,15 +47,9 @@ def _coords(page, k, num_rows: int):
 
 
 def _gather_encode_kernel(pages_ref, storage_ref, data_ref, codes_ref):
-    blk = storage_ref[...]                       # (1, 1, W)
-    data_ref[...] = blk
-    flat = blk.reshape(1, -1)
-    pairs = flat.reshape(1, flat.shape[1] // 2, 2)
-    code = _encode_beats(pairs[..., 0], pairs[..., 1])   # (1, W/2) bytes
-    g = code.reshape(1, code.shape[1] // 4, 4)
-    packed = (g[..., 0] | (g[..., 1] << 8) | (g[..., 2] << 16)
-              | (g[..., 3] << 24)).astype(jnp.uint32)
-    codes_ref[...] = packed.reshape(codes_ref.shape)
+    blk = storage_ref[0]                         # (1, W)
+    data_ref[0] = blk
+    codes_ref[0] = _encode_lanes(blk)            # (1, W/8) packed
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows",))
@@ -68,21 +65,23 @@ def gather_encode(storage: jax.Array, pages: jax.Array, num_rows: int
 
     def storage_index(i, k, pages_ref):
         row, lane = _coords(pages_ref[i], k, num_rows)
-        return row, lane, 0
+        return row * LANES + lane, 0, 0
+
+    def out_index(i, k, pages_ref):
+        return i * DATA_LANES + k, 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n, 8),
+        grid=(n, DATA_LANES),
         in_specs=[pl.BlockSpec((1, 1, W), storage_index)],
-        out_specs=[pl.BlockSpec((1, 1, W), lambda i, k, pages_ref: (i, k, 0)),
-                   pl.BlockSpec((1, 1, W // 8),
-                                lambda i, k, pages_ref: (i, k, 0))],
+        out_specs=[pl.BlockSpec((1, 1, W), out_index),
+                   pl.BlockSpec((1, 1, W // 8), out_index)],
     )
     data, codes = pl.pallas_call(
         _gather_encode_kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n, 8, W), jnp.uint32),
-                   jax.ShapeDtypeStruct((n, 8, W // 8), jnp.uint32)],
+        out_shape=[out_shape((n * DATA_LANES, 1, W), storage, pages),
+                   out_shape((n * DATA_LANES, 1, W // 8), storage, pages)],
         interpret=use_interpret(),
-    )(pages.astype(jnp.int32), storage)
+    )(pages.astype(jnp.int32), pool_views(storage)[0])
     return data.reshape(n, 8 * W), codes.reshape(n, W)

@@ -11,7 +11,8 @@ slices that need it, so a mixed batch is one pass over HBM:
     ``is_secded`` mask are scalar-prefetched (the paged-attention pattern),
   * the storage BlockSpec fetches slice k of page i straight from its
     physical (row, lane) home — the paper's §4.3 bridge-chip translation
-    for mixed layouts as a pure index map,
+    for mixed layouts as a pure index map, over the Mosaic-legal
+    ``(R·9, 1, W)`` view of :func:`pool_views`,
   * a second BlockSpec streams the matching ``W/8``-word sub-range of the
     page's code plane (each W-word slice covers an exact code sub-range,
     as in ``repro.kernels.migrate``); non-SECDED pages fetch a clamped
@@ -77,12 +78,35 @@ def _route(page, num_rows: int, num_shards: int):
     return shard, local
 
 
+def pool_views(storage: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(R, 9, W) pool -> the block views the per-slice kernels stream.
+
+    Mosaic requires a block's last two dims to be (8, 128)-divisible or
+    whole, so a ``(1, 1, W)`` block of the ``(R, 9, W)`` array is refused.
+    Over ``(R·9, 1, W)`` the same (row, lane) slice is a whole trailing
+    ``(1, W)`` block at index ``row·9 + lane``; the code lane gets its own
+    ``(R·8, 1, W/8)`` view, where the code words of slice ``k`` of row ``r``
+    sit at index ``r·8 + k``. The storage format itself is unchanged.
+    """
+    R, lanes, W = storage.shape
+    return (storage.reshape(R * lanes, 1, W),
+            storage[:, CODE_LANE, :].reshape(R * DATA_LANES, 1, W // 8))
+
+
+def out_shape(shape: tuple[int, ...], *operands) -> jax.ShapeDtypeStruct:
+    """uint32 kernel output varying over every mesh axis its operands vary
+    over — what ``shard_map``'s type check needs to accept a kernel called
+    per shard (the sharded pool's per-bank reads)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, jnp.uint32, vma=vma)
+
+
 def _read_correct_kernel(pages_ref, is_sec_ref, storage_ref, codes_ref,
                          out_ref):
     i = pl.program_id(0)
-    blk = storage_ref[...]                                # (1, 1, W)
-    fixed = decode_correct_block(blk, codes_ref[...])
-    out_ref[...] = jnp.where(is_sec_ref[i] != 0, fixed, blk)
+    blk = storage_ref[0]                                  # (1, W)
+    fixed = decode_correct_block(blk, codes_ref[0])
+    out_ref[0] = jnp.where(is_sec_ref[i] != 0, fixed, blk)
 
 
 @functools.partial(jax.jit,
@@ -97,27 +121,28 @@ def read_correct(storage: jax.Array, pages: jax.Array, layout: Layout,
     def storage_index(i, k, pages_ref, sec_ref):
         row, lane = _coords(pages_ref[i], k, layout, num_rows, boundary,
                             ebase)
-        return row, lane, 0
+        return row * LANES + lane, 0, 0
 
     def codes_index(i, k, pages_ref, sec_ref):
         # SECDED codes live at (page, CODE_LANE); non-SECDED pages fetch a
         # clamped in-range block that the kernel masks off.
-        return jnp.clip(pages_ref[i], 0, num_rows - 1), CODE_LANE, k
+        return jnp.clip(pages_ref[i], 0, num_rows - 1) * DATA_LANES + k, 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n, DATA_LANES),
         in_specs=[pl.BlockSpec((1, 1, W), storage_index),
                   pl.BlockSpec((1, 1, W // 8), codes_index)],
-        out_specs=pl.BlockSpec((1, 1, W), lambda i, k, p, s: (i, k, 0)),
+        out_specs=pl.BlockSpec((1, 1, W),
+                               lambda i, k, p, s: (i * DATA_LANES + k, 0, 0)),
     )
     is_sec = ((pages >= boundary) & (pages < num_rows)).astype(jnp.int32)
     out = pl.pallas_call(
         _read_correct_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, DATA_LANES, W), jnp.uint32),
+        out_shape=out_shape((n * DATA_LANES, 1, W), storage, pages),
         interpret=use_interpret(),
-    )(pages.astype(jnp.int32), is_sec, storage, storage)
+    )(pages.astype(jnp.int32), is_sec, *pool_views(storage))
     return out.reshape(n, DATA_LANES * W)
 
 
@@ -126,11 +151,11 @@ def _read_routed_kernel(pages_ref, flags_ref, sid_ref, storage_ref,
     # flags: 0 = not owned by this shard (zeroed), 1 = owned non-SECDED,
     # 2 = owned SECDED (decode-correct)
     i = pl.program_id(0)
-    blk = storage_ref[...]                                # (1, 1, W)
-    fixed = decode_correct_block(blk, codes_ref[...])
+    blk = storage_ref[0]                                  # (1, W)
+    fixed = decode_correct_block(blk, codes_ref[0])
     f = flags_ref[i]
     out = jnp.where(f == 2, fixed, blk)
-    out_ref[...] = jnp.where(f == 0, jnp.zeros_like(out), out)
+    out_ref[0] = jnp.where(f == 0, jnp.zeros_like(out), out)
 
 
 @functools.partial(jax.jit,
@@ -166,19 +191,20 @@ def read_correct_routed(storage: jax.Array, pages: jax.Array, layout: Layout,
         local = jnp.where(shard == sid_ref[0], local, 0)
         row, lane = _coords(local, k, layout, rows_local, boundary_local,
                             ebase)
-        return row, lane, 0
+        return row * LANES + lane, 0, 0
 
     def codes_index(i, k, pages_ref, flags_ref, sid_ref):
         shard, local = _route(pages_ref[i], num_rows, num_shards)
         local = jnp.where(shard == sid_ref[0], local, 0)
-        return jnp.clip(local, 0, rows_local - 1), CODE_LANE, k
+        return jnp.clip(local, 0, rows_local - 1) * DATA_LANES + k, 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n, DATA_LANES),
         in_specs=[pl.BlockSpec((1, 1, W), storage_index),
                   pl.BlockSpec((1, 1, W // 8), codes_index)],
-        out_specs=pl.BlockSpec((1, 1, W), lambda i, k, p, f, s: (i, k, 0)),
+        out_specs=pl.BlockSpec(
+            (1, 1, W), lambda i, k, p, f, s: (i * DATA_LANES + k, 0, 0)),
     )
     # region is shard-invariant (global region == local region), so the
     # owned/SECDED flags vectorise outside the grid walk
@@ -189,7 +215,7 @@ def read_correct_routed(storage: jax.Array, pages: jax.Array, layout: Layout,
     out = pl.pallas_call(
         _read_routed_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, DATA_LANES, W), jnp.uint32),
+        out_shape=out_shape((n * DATA_LANES, 1, W), storage, pages, sid),
         interpret=use_interpret(),
-    )(pages, flags, sid, storage, storage)
+    )(pages, flags, sid, *pool_views(storage))
     return out.reshape(n, DATA_LANES * W)

@@ -16,35 +16,28 @@ from jax.experimental import pallas as pl
 
 from repro.core.layouts import CODE_LANE, DATA_LANES
 from repro.kernels.common import pick_block, use_interpret
-from repro.kernels.secded.kernel import (_encode_beats, _pack4,
-                                         _syndrome_action, _unpack4)
+from repro.kernels.secded.kernel import (_compress, _correct_lanes,
+                                         _expand_codes, _fix_codes,
+                                         _pack_codes, _status)
 
 DEFAULT_BLOCK_ROWS = 16
 
 
 def _scrub_kernel(storage_ref, out_ref, status_ref):
-    block = storage_ref[...]                       # (BR, 9, W)
-    br, _, w = block.shape
-    data = block[:, :DATA_LANES, :].reshape(br, DATA_LANES * w)
-    pairs = data.reshape(br, data.shape[1] // 2, 2)
-    lo, hi = pairs[..., 0], pairs[..., 1]
-    stored = _unpack4(block[:, CODE_LANE, :], lo.shape[1])
-
-    syndrome = (_encode_beats(lo, hi) ^ stored) & jnp.uint32(0xFF)
-    action = _syndrome_action(syndrome)
-    is_data = (action >= 0) & (action < 64)
-    is_code = action >= 64
-    bit = jnp.where(action >= 0, action, 0).astype(jnp.uint32)
-    lo = lo ^ jnp.where(is_data & (bit < 32), jnp.uint32(1) << (bit & 31), 0)
-    hi = hi ^ jnp.where(is_data & (bit >= 32), jnp.uint32(1) << (bit & 31), 0)
-    stored = stored ^ jnp.where(is_code, jnp.uint32(1) << ((bit - 64) & 7), 0)
-
-    fixed = jnp.stack([lo, hi], axis=-1).reshape(br, DATA_LANES, w)
-    out_ref[...] = jnp.concatenate(
-        [fixed, _pack4(stored)[:, None, :]], axis=1)
-    status_ref[...] = jnp.where(
-        action == -1, 0,
-        jnp.where(is_data, 1, jnp.where(is_code, 2, 3))).astype(jnp.int32)
+    # slice k of a row (lane k's W words) owns code words [k·W/8, (k+1)·W/8)
+    # of the code lane and beats [k·W/2, (k+1)·W/2) of the status row
+    w = storage_ref.shape[2]
+    cw, beats = w // DATA_LANES, w // 2
+    packed = storage_ref[:, CODE_LANE, :]          # (BR, W)
+    codes = []
+    for k in range(DATA_LANES):
+        stored = _expand_codes(packed[:, k * cw:(k + 1) * cw], w)
+        fixed, action = _correct_lanes(storage_ref[:, k, :], stored)
+        out_ref[:, k, :] = fixed
+        codes.append(_pack_codes(_fix_codes(stored, action)))
+        status_ref[:, k * beats:(k + 1) * beats] = _compress(
+            _status(action), 2, beats)[:, :beats]
+    out_ref[:, CODE_LANE, :] = jnp.concatenate(codes, axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows",))

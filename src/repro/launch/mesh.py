@@ -12,19 +12,28 @@ Mesh axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int) -> tuple[AxisType, ...]:
+    """Auto axis types: ``jax.make_mesh`` now defaults to Explicit, which
+    puts shardings into array types and rejects the plain gathers and
+    scatters of the data plane (``x[inv]``, ``.at[inv].set``)."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model_axis: int = 1):
     """A mesh over whatever devices exist (tests / single host)."""
     n = len(jax.devices())
     assert n % model_axis == 0
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def make_banks_mesh(num_banks: int):
@@ -41,7 +50,7 @@ def make_banks_mesh(num_banks: int):
             f"{len(devices)}; on CPU set "
             "XLA_FLAGS=--xla_force_host_platform_device_count")
     return jax.make_mesh((num_banks,), ("banks",),
-                         devices=devices[:num_banks])
+                         devices=devices[:num_banks], axis_types=_auto(1))
 
 
 # TPU v5e hardware constants (roofline denominators; see EXPERIMENTS.md)

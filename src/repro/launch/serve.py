@@ -13,6 +13,7 @@ import json
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import Engine, ServeRequest
 
 
@@ -35,6 +36,7 @@ def main() -> None:
                     help="rows kept SECDED in cream mode (the paid tier's "
                          "frames; multiple of 8)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -61,10 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:                                    # jax >= 0.6 moved it to the top level
-    from jax import shard_map           # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import pool as pool_lib
 from repro.core.layouts import (GROUP_ROWS, LANES, Layout, extra_page_count)

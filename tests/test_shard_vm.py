@@ -129,3 +129,33 @@ def test_sequence_cache_on_sharded_pool():
     for sid, blob in blobs.items():
         np.testing.assert_array_equal(out[sid], blob)
     assert cache.stats.device_hits == 6
+
+
+@pytest.mark.parametrize("verb", ["read", "write"])
+def test_planned_access_on_banks_mesh(verb):
+    """The planned (concrete-id) verbs on a ``make_banks_mesh`` pool.
+
+    ``jax.make_mesh`` defaults to Explicit axis types, under which the
+    planned path's permutation gather/scatter raised ``ShardingTypeError``;
+    the banks mesh is built with Auto axes. Each case runs one planned verb
+    and checks it through the traced (fused) path of the other verb.
+    """
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_banks_mesh
+    from repro.shard.pool import make_sharded_pool
+    mesh = make_banks_mesh(4)
+    assert mesh.axis_types == (AxisType.Auto,)
+    pool = make_sharded_pool(128, Layout.INTERWRAP, 64, num_shards=4,
+                             row_words=ROW_WORDS, mesh=mesh)
+    pages = np.array([0, 5, 63, 64, 99, 127, 128, 135], np.int32)
+    blob = np.random.default_rng(5).integers(
+        0, 2**32, (len(pages), pool.page_words), dtype=np.uint32)
+    if verb == "write":
+        pool = pool.write(pages, blob)
+        got = jax.jit(lambda p, ids: p.read(ids))(pool, jnp.asarray(pages))
+    else:
+        pool = jax.jit(lambda p, ids, d: p.write(ids, d))(
+            pool, jnp.asarray(pages), jnp.asarray(blob))
+        got = pool.read(pages)
+    np.testing.assert_array_equal(np.asarray(got), blob)
