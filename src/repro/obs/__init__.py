@@ -8,11 +8,13 @@ by the existing fused reads and *folded* into the registry between steps):
     gauges / histograms with labelled series (pool, reliability class,
     tier, region), a Prometheus-style text exposition, and fold helpers
     for device-side status accumulators;
-  * :mod:`repro.obs.tracing` — nestable spans with a Perfetto /
-    chrome-tracing JSON exporter, instrumenting the named hot paths
-    (``Engine.step`` gather/compute/scatter, the shard router dispatch
-    and ``ppermute`` migration ring, ``repartition_with_migration``,
-    scrub sweeps, objcache batched get/set);
+  * :mod:`repro.obs.tracing` — nestable spans, each also a
+    ``jax.profiler`` annotation on the device trace's clock, with a
+    Perfetto / chrome-tracing JSON exporter, instrumenting the named hot
+    paths (``Engine.poll`` and its admission, prefill and decode-step
+    phases, the shard dispatch and ``ppermute`` migration ring,
+    ``repartition_with_migration``, scrub sweeps, objcache batched
+    get/set);
   * :mod:`repro.obs.slo` + :mod:`repro.obs.dashboard` — per-reliability-
     class SLO tracking (uncorrectable reads on SECDED frames must be 0;
     capacity reclaimed rides the boundary register) and a terminal

@@ -1,6 +1,15 @@
-"""Hot-path tracing: nestable spans with a Perfetto/chrome-tracing export.
+"""Hot-path tracing: nestable spans on the profiler's clock, with a
+Perfetto/chrome-tracing export.
 
-Spans are recorded as chrome-tracing *complete events* (``"ph": "X"``)
+While tracing is enabled every span also opens a
+``jax.profiler.TraceAnnotation`` of the same name and arguments, so a span
+opened inside a ``jax.profiler`` session lands in that session's
+``.xplane.pb`` beside the device's operations, on the same clock: an idle
+gap on the device can be laid against the host work that spans it. A span
+only marks host work that happens anyway; nothing here waits for the
+device.
+
+Spans are also recorded as chrome-tracing *complete events* (``"ph": "X"``)
 with microsecond timestamps, so the export loads directly in Perfetto
 (https://ui.perfetto.dev) or ``chrome://tracing``. Nesting comes for free:
 chrome's trace viewer stacks events on the same tid by containment, and a
@@ -10,15 +19,9 @@ want it explicitly.
 Disabled (the default), :func:`span` returns a shared null context — one
 boolean read per call site, no allocation, no clock reads — so tracing can
 stay compiled into every hot path.
-
-The compile-vs-execute helper :func:`traced_call` wraps a jitted callable
-in two spans: ``<name>.dispatch`` (tracing + compilation on first call,
-then just dispatch) and ``<name>.block_until_ready`` (device execution),
-which is how the benchmarks' ``--profile`` mode attributes kernel time.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
@@ -43,7 +46,7 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "args", "t0")
+    __slots__ = ("tracer", "name", "args", "t0", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
@@ -53,11 +56,15 @@ class _Span:
     def __enter__(self):
         tl = self.tracer._tls
         tl.depth = getattr(tl, "depth", 0) + 1
+        self.annotation = jax.profiler.TraceAnnotation(self.name,
+                                                       **self.args)
+        self.annotation.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur_us = (time.perf_counter_ns() - self.t0) / 1e3
+        self.annotation.__exit__(*exc)
         tl = self.tracer._tls
         depth = getattr(tl, "depth", 1)
         tl.depth = depth - 1
@@ -84,17 +91,6 @@ class Tracer:
         if not self.enabled:
             return _NULL
         return _Span(self, name, args)
-
-    def instant(self, name: str, **args) -> None:
-        """A zero-duration marker event."""
-        if not self.enabled:
-            return
-        self._events.append({
-            "name": name, "ph": "i", "cat": "cream", "s": "t",
-            "ts": time.perf_counter_ns() / 1e3,
-            "pid": os.getpid(), "tid": threading.get_ident(),
-            "args": args,
-        })
 
     @property
     def events(self) -> list[dict]:
@@ -147,54 +143,9 @@ def span(name: str, **args):
     return _Span(TRACER, name, args)
 
 
-def instant(name: str, **args) -> None:
-    TRACER.instant(name, **args)
-
-
 def reset() -> None:
     TRACER.reset()
 
 
 def export(path: str) -> None:
     TRACER.export(path)
-
-
-def traced_call(name: str, fn, *args, **kwargs):
-    """Run ``fn`` under dispatch / block_until_ready spans.
-
-    ``<name>.dispatch`` covers tracing+compilation (dominant on the first
-    call for a given shape) plus async dispatch; ``<name>.block_until_ready``
-    covers device execution. With tracing disabled this is a plain call —
-    no blocking, no spans — so it is safe on hot paths.
-    """
-    if not TRACER.enabled:
-        return fn(*args, **kwargs)
-    with span(f"{name}.dispatch"):
-        out = fn(*args, **kwargs)
-    with span(f"{name}.block_until_ready"):
-        jax.block_until_ready(out)
-    return out
-
-
-@contextlib.contextmanager
-def blocked_span(name: str, **args):
-    """Span that blocks on the values the body hands back via ``hold``.
-
-    Usage::
-
-        with blocked_span("engine.step.gather") as hold:
-            pages = pool.read(phys)
-            hold(pages)
-
-    ensures the span's duration covers device execution, not just async
-    dispatch. When tracing is disabled the body still runs; ``hold`` is a
-    no-op and nothing blocks.
-    """
-    if not TRACER.enabled:
-        yield lambda *_: None
-        return
-    with span(name, **args):
-        held = []
-        yield held.append
-        if held:
-            jax.block_until_ready(held)

@@ -162,6 +162,10 @@ class Engine:
         self._lens = np.zeros(max_batch, np.int32)
         self._toks = np.zeros(max_batch, np.int32)
         self.steps = 0
+        # cumulative over decode steps: pages the gather read, and those
+        # of them that hold live tokens (the rest is block-table padding)
+        self.pages_gathered = 0
+        self.pages_gathered_live = 0
         self._prefill = jax.jit(
             lambda p, toks: self.model.prefill(p, toks, max_len))
         self._attend = jax.jit(self._attend_fn)
@@ -344,7 +348,7 @@ class Engine:
 
     # -- the serving loop ------------------------------------------------------
     def _do_prefill(self, slot: int, req: ServeRequest, sess) -> None:
-        with obs_tracing.span("engine.prefill", slot=slot,
+        with obs_tracing.span("engine.prefill", seq_id=req.seq_id,
                               prompt=len(req.prompt), tier=req.tier):
             self._do_prefill_impl(slot, req, sess)
         if obs_metrics.enabled():
@@ -369,116 +373,126 @@ class Engine:
         self.vm.pools[self.pool_name] = self.pool.write(ids, data)
         sess.cache_len = p
         sess.last_tok = int(jnp.argmax(logits[0, -1]))
+        if not req.t_first:
+            req.t_first = time.perf_counter()
         req.generated.append(sess.last_tok)
         self._lens[slot] = sess.cache_len
         self._toks[slot] = sess.last_tok
 
     def step(self) -> list[ServeRequest]:
         """One decode step over every bound slot: one page gather, one
-        model dispatch, one page scatter. Returns requests that finished."""
-        self.sched.ensure_step()
-        if obs_memprof.enabled():
-            obs_memprof.next_step()     # one profiler step per decode step
-        rows = np.asarray([s.row if s is not None else -1
-                           for s in self.sched.slots])
-        active = rows >= 0
-        if not active.any():
-            return []
-        lens = np.where(active, self._lens, 0).astype(np.int32)
-        toks = np.where(active, self._toks, 0).astype(np.int32)
-        with obs_tracing.span("serve.router.dispatch",
-                              slots=int(active.sum())):
-            phys = self.kv.gather_phys(rows)                # (B, L, maxB)
-        counts = None
-        with obs_tracing.blocked_span("engine.step.gather",
-                                      pages=int(phys.size)) as hold:
-            if obs_metrics.enabled():
-                pages, counts = self._gather_pages_counts(phys.reshape(-1))
-            else:
-                pages = self._gather_pages(phys.reshape(-1))  # ONE gather
-            hold(pages)
-        pending = self._pending_migration
-        from repro.shard.pool import ShardedPool
-        if pending is not None and isinstance(self.pool, ShardedPool):
-            # ring overlapped with compute: ONE fused program
-            src, dst = pending
-            self._pending_migration = None
-            if obs_metrics.enabled():
-                obs_metrics.counter(
-                    obs_metrics.NAME_SHARD_RING_PAGES,
-                    "pages exchanged over the ppermute migration ring"
-                ).inc(int(src.shape[0]))
-            with obs_tracing.blocked_span("engine.step.compute_ring",
-                                          ring_pages=int(src.shape[0])) \
-                    as hold:
-                _, nxt, cur_pages, new_pool = self._attend_ring(
-                    self.params, pages, jnp.asarray(lens),
-                    jnp.asarray(toks), self.pool,
-                    jnp.asarray(src), jnp.asarray(dst))
-                self.vm.pools[self.pool_name] = new_pool
-                hold(nxt)
-        else:
-            with obs_tracing.blocked_span("engine.step.compute") as hold:
-                _, nxt, cur_pages = self._attend(self.params, pages,
-                                                 jnp.asarray(lens),
-                                                 jnp.asarray(toks))
-                hold(nxt)
-            if pending is not None:
+        model dispatch, one page scatter. Returns requests that finished.
+
+        Its spans (``engine.step`` and the children ``.plan``, ``.gather``,
+        ``.compute`` or ``.compute_ring``, ``.scatter``, ``.sync``,
+        ``.emit``) each cover host work the step does anyway; only
+        ``.sync``, the read of the next tokens, waits for the device."""
+        with obs_tracing.span("engine.step"):
+            with obs_tracing.span("engine.step.plan"):
+                self.sched.ensure_step()
+                if obs_memprof.enabled():
+                    obs_memprof.next_step()   # one per decode step
+                rows = np.asarray([s.row if s is not None else -1
+                                   for s in self.sched.slots])
+                active = rows >= 0
+                if not active.any():
+                    return []
+                lens = np.where(active, self._lens, 0).astype(np.int32)
+                toks = np.where(active, self._toks, 0).astype(np.int32)
+                phys = self.kv.gather_phys(rows)                # (B, L, maxB)
+                self.pages_gathered += int(phys.size)
+                # live: blocks < ceil((len + 1) / block_tokens) of bound slots
+                self.pages_gathered_live += self.n_layers * int(
+                    (-(-(lens[active] + 1) // self._bt)).sum())
+            counts = None
+            with obs_tracing.span("engine.step.gather", pages=int(phys.size)):
+                if obs_metrics.enabled():
+                    pages, counts = self._gather_pages_counts(phys.reshape(-1))
+                else:
+                    pages = self._gather_pages(phys.reshape(-1))  # ONE gather
+            pending = self._pending_migration
+            from repro.shard.pool import ShardedPool
+            if pending is not None and isinstance(self.pool, ShardedPool):
+                # ring overlapped with compute: ONE fused program
+                src, dst = pending
                 self._pending_migration = None
-                self.vm.pools[self.pool_name] = self.pool.migrate(
-                    pending[0], pending[1])
-        with obs_tracing.blocked_span("engine.step.scatter") as hold:
-            cur_ids = self.kv.current_block_phys(rows, lens)  # (B, L)
-            self.vm.pools[self.pool_name] = self.pool.write(
-                cur_ids.reshape(-1), cur_pages)             # ONE scatter
-            hold(self.pool.storage)
-        nxt = np.asarray(nxt)
-        self.steps += 1
-        if counts is not None:
-            obs_metrics.fold_read_status(counts)
-        finished = []
-        tokens_by_tier: dict[str, int] = {}
-        for slot in np.flatnonzero(active):
-            sess = self.sched.slots[slot]
-            sess.cache_len += 1
-            sess.last_tok = int(nxt[slot])
-            sess.req.generated.append(sess.last_tok)
-            self._lens[slot] = sess.cache_len
-            self._toks[slot] = sess.last_tok
-            tier = sess.req.tier
-            tokens_by_tier[tier] = tokens_by_tier.get(tier, 0) + 1
-            if len(sess.req.generated) >= sess.req.max_new:
-                finished.append(self.sched.finish(slot))
-        if obs_metrics.enabled():
-            obs_metrics.counter(obs_metrics.NAME_DECODE_STEPS,
-                                "batched decode steps executed").inc()
-            tok = obs_metrics.counter(
-                obs_metrics.NAME_TOKENS_DECODED,
-                "tokens decoded, by request tier", labels=("tier",))
-            for tier, n in tokens_by_tier.items():
-                tok.labels(tier=tier).inc(n)
-        return finished
+                if obs_metrics.enabled():
+                    obs_metrics.counter(
+                        obs_metrics.NAME_SHARD_RING_PAGES,
+                        "pages exchanged over the ppermute migration ring"
+                    ).inc(int(src.shape[0]))
+                with obs_tracing.span("engine.step.compute_ring",
+                                      ring_pages=int(src.shape[0])):
+                    _, nxt, cur_pages, new_pool = self._attend_ring(
+                        self.params, pages, jnp.asarray(lens),
+                        jnp.asarray(toks), self.pool,
+                        jnp.asarray(src), jnp.asarray(dst))
+                    self.vm.pools[self.pool_name] = new_pool
+            else:
+                with obs_tracing.span("engine.step.compute"):
+                    _, nxt, cur_pages = self._attend(self.params, pages,
+                                                     jnp.asarray(lens),
+                                                     jnp.asarray(toks))
+                if pending is not None:
+                    self._pending_migration = None
+                    self.vm.pools[self.pool_name] = self.pool.migrate(
+                        pending[0], pending[1])
+            with obs_tracing.span("engine.step.scatter"):
+                cur_ids = self.kv.current_block_phys(rows, lens)  # (B, L)
+                self.vm.pools[self.pool_name] = self.pool.write(
+                    cur_ids.reshape(-1), cur_pages)             # ONE scatter
+            with obs_tracing.span("engine.step.sync"):
+                nxt = np.asarray(nxt)
+            self.steps += 1
+            with obs_tracing.span("engine.step.emit"):
+                if counts is not None:
+                    obs_metrics.fold_read_status(counts)
+                finished = []
+                tokens_by_tier: dict[str, int] = {}
+                for slot in np.flatnonzero(active):
+                    sess = self.sched.slots[slot]
+                    sess.cache_len += 1
+                    sess.last_tok = int(nxt[slot])
+                    if not sess.req.t_first:
+                        sess.req.t_first = time.perf_counter()
+                    sess.req.generated.append(sess.last_tok)
+                    self._lens[slot] = sess.cache_len
+                    self._toks[slot] = sess.last_tok
+                    tier = sess.req.tier
+                    tokens_by_tier[tier] = tokens_by_tier.get(tier, 0) + 1
+                    if len(sess.req.generated) >= sess.req.max_new:
+                        finished.append(self.sched.finish(slot))
+            if obs_metrics.enabled():
+                obs_metrics.counter(obs_metrics.NAME_DECODE_STEPS,
+                                    "batched decode steps executed").inc()
+                tok = obs_metrics.counter(
+                    obs_metrics.NAME_TOKENS_DECODED,
+                    "tokens decoded, by request tier", labels=("tier",))
+                for tier, n in tokens_by_tier.items():
+                    tok.labels(tier=tier).inc(n)
+            return finished
 
     def poll(self) -> list[ServeRequest]:
         """One serving-loop iteration: an admission pass (prefilling the
         newly admitted sessions) followed by one batched decode step.
         Returns requests that completed; raises on an unserveable queue."""
-        admitted = self.sched.tick()
-        done: list[ServeRequest] = []
-        for adm in admitted:
-            if adm.is_prefill:
-                self._do_prefill(adm.slot, adm.req, adm.session)
-                if len(adm.req.generated) >= adm.req.max_new:
-                    done.append(self.sched.finish(adm.slot))
-            else:
-                self._lens[adm.slot] = adm.session.cache_len
-                self._toks[adm.slot] = adm.session.last_tok
-        if self.sched.active_slots():
-            done.extend(self.step())
-        elif not admitted and self.sched.waiting:
-            raise RuntimeError(
-                "deadlock: waiting requests cannot be admitted "
-                f"({self.sched.stats})")
+        with obs_tracing.span("engine.poll"):
+            admitted = self.sched.tick()
+            done: list[ServeRequest] = []
+            for adm in admitted:
+                if adm.is_prefill:
+                    self._do_prefill(adm.slot, adm.req, adm.session)
+                    if len(adm.req.generated) >= adm.req.max_new:
+                        done.append(self.sched.finish(adm.slot))
+                else:
+                    self._lens[adm.slot] = adm.session.cache_len
+                    self._toks[adm.slot] = adm.session.last_tok
+            if self.sched.active_slots():
+                done.extend(self.step())
+            elif not admitted and self.sched.waiting:
+                raise RuntimeError(
+                    "deadlock: waiting requests cannot be admitted "
+                    f"({self.sched.stats})")
         return done
 
     def serve(self, requests: list[ServeRequest]) -> dict:

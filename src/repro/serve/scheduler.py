@@ -31,6 +31,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.obs import tracing
 from repro.serve.paged_kv import PagedKV
 
 
@@ -41,6 +42,12 @@ class ServeRequest:
     ``prompt`` seeds the session's KV on first contact (and on a reset
     after the session's block table fills); continuation turns reuse the
     session's parked KV and decode straight away.
+
+    The ``t_*`` stamps are ``time.perf_counter()`` readings, 0 until set:
+    ``t_submit`` at :meth:`Scheduler.submit`, ``t_admit`` when a tick
+    first binds the request to a slot, ``t_first`` when its first token
+    is on the host (after the prefill's argmax, or a continuation's first
+    decode step), ``t_done`` at :meth:`Scheduler.finish`.
     """
     seq_id: str
     prompt: np.ndarray
@@ -48,6 +55,8 @@ class ServeRequest:
     tier: str = "batch"
     generated: list[int] = field(default_factory=list)
     t_submit: float = 0.0
+    t_admit: float = 0.0
+    t_first: float = 0.0
     t_done: float = 0.0
 
     @property
@@ -113,27 +122,30 @@ class Scheduler:
         """One admission pass: bind as many waiting requests to free slots
         as device capacity allows. Returns the new bindings; the engine
         prefills the ``is_prefill`` ones."""
-        self._clock += 1
-        out: list[Admission] = []
-        i = 0
-        while i < len(self.waiting) and None in self.slots:
-            req = self.waiting[i]
-            sess = self.sessions.get(req.seq_id)
-            if sess is not None and sess.slot is not None:
-                i += 1          # session busy: later sessions may overtake
-                continue
-            act = self._activate(req)
-            if act is None:     # out of device frames: head-of-line waits
-                break
-            sess, is_prefill = act
-            slot = self.slots.index(None)
-            self.slots[slot] = sess
-            sess.slot = slot
-            sess.req = req
-            sess.last_use = self._clock
-            self.waiting.pop(i)
-            out.append(Admission(slot, req, sess, is_prefill))
-        return out
+        with tracing.span("sched.tick"):
+            self._clock += 1
+            out: list[Admission] = []
+            i = 0
+            while i < len(self.waiting) and None in self.slots:
+                req = self.waiting[i]
+                sess = self.sessions.get(req.seq_id)
+                if sess is not None and sess.slot is not None:
+                    i += 1          # session busy: later sessions may overtake
+                    continue
+                act = self._activate(req)
+                if act is None:     # out of device frames: head-of-line waits
+                    break
+                sess, is_prefill = act
+                slot = self.slots.index(None)
+                self.slots[slot] = sess
+                sess.slot = slot
+                sess.req = req
+                sess.last_use = self._clock
+                if not req.t_admit:
+                    req.t_admit = time.perf_counter()
+                self.waiting.pop(i)
+                out.append(Admission(slot, req, sess, is_prefill))
+            return out
 
     def ensure_step(self) -> list[int]:
         """Grow every bound session's block table for one more token,
