@@ -162,10 +162,12 @@ class Engine:
         self._lens = np.zeros(max_batch, np.int32)
         self._toks = np.zeros(max_batch, np.int32)
         self.steps = 0
-        # cumulative over decode steps: pages the gather read, and those
-        # of them that hold live tokens (the rest is block-table padding)
+        # cumulative over decode steps: pages the gather read, those of
+        # them that hold live tokens (the rest is block-table padding), and
+        # those in the SECDED region (the ones the gather decodes)
         self.pages_gathered = 0
         self.pages_gathered_live = 0
+        self.pages_gathered_secded = 0
         self._prefill = jax.jit(
             lambda p, toks: self.model.prefill(p, toks, max_len))
         self._attend = jax.jit(self._attend_fn)
@@ -404,8 +406,12 @@ class Engine:
                 # live: blocks < ceil((len + 1) / block_tokens) of bound slots
                 self.pages_gathered_live += self.n_layers * int(
                     (-(-(lens[active] + 1) // self._bt)).sum())
+                secded = int(((phys >= self.pool.boundary)
+                              & (phys < self.pool.num_rows)).sum())
+                self.pages_gathered_secded += secded
             counts = None
-            with obs_tracing.span("engine.step.gather", pages=int(phys.size)):
+            with obs_tracing.span("engine.step.gather", pages=int(phys.size),
+                                  secded=secded):
                 if obs_metrics.enabled():
                     pages, counts = self._gather_pages_counts(phys.reshape(-1))
                 else:

@@ -32,12 +32,59 @@ def _filled_pool(layout, boundary):
 @pytest.mark.parametrize("boundary", [0, 8, 16])
 def test_kernel_matches_ref_all_modes(layout, boundary):
     pool = _filled_pool(layout, boundary)
-    ids = jnp.asarray(list(RNG.permutation(pool.num_pages)[:7]), jnp.int32)
+    # 13 ids, as in the block-table cases below: one compile serves both
+    ids = jnp.asarray(list(RNG.permutation(pool.num_pages)[:13]), jnp.int32)
     d_ref = ref.read_correct(pool.storage, ids, layout, pool.num_rows,
                              boundary)
     d_ker = kernel.read_correct(pool.storage, ids, layout, pool.num_rows,
                                 boundary)
     np.testing.assert_array_equal(np.asarray(d_ref), np.asarray(d_ker))
+
+
+def _block_table_ids(case, pool, flipped):
+    """13 page ids shaped like a decode step's padded block tables: live
+    ids, then runs of one padding id (the scratch page), which the kernel
+    fetches once and copies. 13 is not a multiple of
+    ``kernel.PAGES_PER_STEP``, and every run crosses a grid step.
+    ``flipped`` comes right after a run."""
+    live = [p for p in RNG.permutation(pool.num_pages) if p != flipped]
+    pad = int(live[-1])
+    if case == "padding_run":
+        ids = [live[0]] + [pad] * 9 + [live[1], live[2], pad]
+    elif case == "live_repeats":
+        ids = [live[0], live[1], pad, pad, pad, live[2], live[2], pad, pad,
+               pad, pad, live[3], pad]
+    else:                                    # "flip_after_run"
+        ids = [live[0], pad, pad, pad, pad, pad, flipped, live[1], live[1],
+               pad, pad, pad, pad]
+    assert len(ids) % kernel.PAGES_PER_STEP
+    return jnp.asarray(ids, jnp.int32)
+
+
+@pytest.mark.parametrize("case", ["padding_run", "live_repeats",
+                                  "flip_after_run"])
+@pytest.mark.parametrize("layout", ALL_LAYOUTS)
+@pytest.mark.parametrize("boundary", [0, 8, 16])
+def test_kernel_matches_ref_on_block_tables(layout, boundary, case):
+    """Repeated ids are copied, not fetched again, and a decoded page
+    right after a run is decoded from its own data: bit-exact with the
+    oracle, and a planted flip in a SECDED page comes back corrected."""
+    pool = _filled_pool(layout, boundary)
+    flipped = min(boundary, pool.num_rows - 1)   # SECDED where any row is
+    ids = _block_table_ids(case, pool, flipped)
+    storage = pool.storage
+    clean, _ = P.read_page(pool, flipped)
+    if case == "flip_after_run":
+        arr = np.asarray(storage).copy()
+        arr[flipped, 3, 9] ^= np.uint32(1 << 17)   # its row, data lane 3
+        storage = jnp.asarray(arr)
+    d_ref = ref.read_correct(storage, ids, layout, pool.num_rows, boundary)
+    d_ker = kernel.read_correct(storage, ids, layout, pool.num_rows,
+                                boundary)
+    np.testing.assert_array_equal(np.asarray(d_ref), np.asarray(d_ker))
+    if case == "flip_after_run" and boundary < pool.num_rows:
+        np.testing.assert_array_equal(np.asarray(d_ker[6]),
+                                      np.asarray(clean))
 
 
 def test_kernel_matches_page_reads_mixed_ids():

@@ -195,3 +195,26 @@ def test_gather_page_counters():
     assert eng.pages_gathered_live == eng.n_layers * sum(
         -(-(n + 1) // bt) for lens in lens_seen for n in lens)
     assert 0 < eng.pages_gathered_live < eng.pages_gathered
+
+
+def test_gather_secded_counter():
+    """``pages_gathered_secded`` counts the gathered ids in the SECDED
+    region ``[boundary, num_rows)``: the paid session's live pages, and
+    nothing of the CREAM batch session or the padding."""
+    eng = _tiny_engine()
+    expect = []
+    real = eng.kv.gather_phys
+
+    def spy(rows):
+        phys = real(rows)
+        if len(rows) == eng.max_batch:          # the step's lookup
+            pool = eng.pool
+            expect.append(int(((phys >= pool.boundary)
+                               & (phys < pool.num_rows)).sum()))
+        return phys
+
+    eng.kv.gather_phys = spy
+    _serve_turns(eng)
+    assert eng.steps == len(expect) > 0
+    assert eng.pages_gathered_secded == sum(expect)
+    assert 0 < eng.pages_gathered_secded < eng.pages_gathered_live

@@ -23,6 +23,8 @@ ROWS = 12800                   # local pool rows (4 banks x 3200)
 CREAM_ROWS = 2048              # the smoke's CREAM region; the rest SECDED
 SHARDS = 4
 GATHER = 8 * 28 * 64           # max_batch x layers x max_blocks
+BENCH_ROWS = 25600             # the chip benchmark's pool
+BENCH_SECDED_ROWS = 7168       # of it, the paid tier's SECDED rows
 HBM_BYTES = 16 * 2**30         # one v5e chip
 
 
@@ -95,6 +97,27 @@ def test_mixed_read_correct_compiles(native, one_chip, boundary):
     _check(_compile(kernel.read_correct, ("layout", "num_rows", "boundary"),
                     _pool(one_chip), _ids(GATHER, one_chip),
                     Layout.INTERWRAP, ROWS, boundary))
+
+
+@pytest.mark.parametrize("boundary", [BENCH_ROWS - BENCH_SECDED_ROWS, 0],
+                         ids=["cream", "secded"])
+def test_mixed_read_correct_reads_the_pool_in_place(native, one_chip,
+                                                    boundary):
+    """At the benchmark's pool the gather reads the storage through its
+    plane view: no copy or transpose of the pool, next to no temporaries
+    (the per-slice kernel's relayout took ~5.2 GB here)."""
+    from repro.kernels.mixed import kernel
+    compiled = _compile(kernel.read_correct,
+                        ("layout", "num_rows", "boundary"),
+                        _pool(one_chip, BENCH_ROWS), _ids(GATHER, one_chip),
+                        Layout.INTERWRAP, BENCH_ROWS, boundary)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    relayouts = [line for line in text.splitlines()
+                 if ("copy(" in line or "transpose(" in line)
+                 and str(BENCH_ROWS) in line]
+    assert not relayouts, relayouts
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 def test_mixed_read_correct_routed_compiles(native, one_chip):
