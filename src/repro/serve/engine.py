@@ -226,8 +226,7 @@ class Engine:
                           self.kv.max_blocks, self._bt)
         hkv, hd = cfg.num_kv_heads, cfg.head_dim_
         pages = pages_u32.reshape(B, L, maxB, -1)
-        used, tail = pages[..., :kvw], pages[..., kvw:]
-        kvv = jax.lax.bitcast_convert_type(used, jnp.float32)
+        kvv = jax.lax.bitcast_convert_type(pages[..., :kvw], jnp.float32)
         kvv = kvv.reshape(B, L, maxB, 2, bt, hkv, hd)
         k = kvv[:, :, :, 0].transpose(1, 0, 2, 3, 4, 5) \
             .reshape(L, B, maxB * bt, hkv, hd)
@@ -235,25 +234,24 @@ class Engine:
             .reshape(L, B, maxB * bt, hkv, hd)
         logits, _, (k_new, v_new) = transformer.decode_step_paged(
             params, cfg, {"cache_len": lens}, toks, (k, v))
-        # write-back: insert the new token into each slot's current block
+        # write-back: pick each slot's current block as B*L whole page rows
+        # (row (b*L + l)*maxB + blk[b]), then insert the new token into them
         blk = lens // bt
         off = lens - blk * bt
-        idx = jnp.broadcast_to(blk.reshape(B, 1, 1, 1, 1, 1, 1),
-                               (B, L, 1, 2, bt, hkv, hd))
-        curr = jnp.take_along_axis(kvv, idx, axis=2)[:, :, 0]
+        rows = jnp.arange(B * L).reshape(B, L) * maxB + blk[:, None]
+        cur = jnp.take(pages_u32, rows.reshape(-1), axis=0)   # (B*L, pw)
+        curr = jax.lax.bitcast_convert_type(cur[:, :kvw], jnp.float32) \
+            .reshape(B, L, 2, bt, hkv, hd)
         new_tok = jnp.stack([k_new.transpose(1, 0, 2, 3),
                              v_new.transpose(1, 0, 2, 3)], axis=2)
         onehot = jnp.arange(bt) == off[:, None]              # (B, bt)
         curr = jnp.where(onehot[:, None, None, :, None, None],
                          new_tok[:, :, :, None], curr)
         cur_used = jax.lax.bitcast_convert_type(curr, jnp.uint32) \
-            .reshape(B, L, kvw)
-        tidx = jnp.broadcast_to(blk.reshape(B, 1, 1, 1),
-                                (B, L, 1, tail.shape[-1]))
-        cur_tail = jnp.take_along_axis(tail, tidx, axis=2)[:, :, 0]
-        cur_pages = jnp.concatenate([cur_used, cur_tail], axis=-1)
+            .reshape(B * L, kvw)
+        cur_pages = jnp.concatenate([cur_used, cur[:, kvw:]], axis=-1)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return logits, nxt, cur_pages.reshape(B * L, -1)
+        return logits, nxt, cur_pages
 
     def _attend_ring_fn(self, params, pages_u32, lens, toks, pool, src, dst):
         """:meth:`_attend_fn` fused with the sharded pool's ``ppermute``

@@ -9,6 +9,8 @@ The paged engine's whole value rests on two claims:
     by a mid-decode repartition that shrinks the weak-class pool — and
     resuming it later is bit-exact (same tokens as an unpreempted run).
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +20,7 @@ from repro.configs.base import ModelConfig
 from repro.core.layouts import Layout
 from repro.core.pool import PoolState
 from repro.core.protection import Protection
+from repro.models import transformer
 from repro.serve import Engine, ServeRequest
 from repro.vm.address_space import VirtualMemory
 from repro.vm.migration import MigrationEngine
@@ -158,6 +161,55 @@ def test_one_dispatch_per_step_sharded(monkeypatch):
     monkeypatch.setattr(shard_pool, "_write_planned_jitted", counting_write)
     eng.poll()                      # a pure decode step
     assert calls == {"read": 1, "write": 1}
+
+
+# head_dim 12: a token is 48 words, so a 512-word page holds 10 tokens and
+# keeps a 32-word tail that is not K/V
+CFG_TAIL = dataclasses.replace(CFG, name="serve-test-tail", head_dim=12)
+MAX_LEN = 32
+
+
+@pytest.mark.parametrize("cfg", [CFG, CFG_TAIL], ids=["no_tail", "tail"])
+@pytest.mark.parametrize("where", ["zero", "block_end", "block_start",
+                                   "middle", "last"])
+def test_attend_write_back_matches_numpy(monkeypatch, cfg, where):
+    """The pages the model step returns for the scatter are each bound
+    slot's current block (``lens // bt`` of every layer) with the step's new
+    K and V written at ``lens % bt`` and the page's tail as it was, bit for
+    bit; slot 3 is inactive (length 0, every block the scratch page)."""
+    eng = Engine(cfg, max_batch=4, max_len=MAX_LEN, num_rows=64,
+                 row_words=64, seed=0)
+    B, L, maxB, bt = eng.max_batch, eng.n_layers, eng.kv.max_blocks, eng._bt
+    kvw, pw = eng.kv.kv_words, eng.kv.page_words
+    hkv, hd = cfg.num_kv_heads, cfg.head_dim_
+    assert (pw > kvw) == (cfg is CFG_TAIL)
+    n = {"zero": 0, "block_end": bt - 1, "block_start": bt,
+         "middle": MAX_LEN // 2 + 1, "last": MAX_LEN - 1}[where]
+    lens = np.array([n, MAX_LEN - 1 - n, (n + bt // 2) % MAX_LEN, 0],
+                    np.int32)
+    rng = np.random.default_rng(n)
+    pages = rng.integers(0, 2**32, (B, L, maxB, pw), dtype=np.uint32)
+    pages[..., :kvw] = rng.standard_normal(
+        (B, L, maxB, kvw)).astype(np.float32).view(np.uint32)
+    pages[3] = pages[3, 0, 0]                       # the scratch page
+    k_new = rng.standard_normal((L, B, hkv, hd)).astype(np.float32)
+    v_new = rng.standard_normal((L, B, hkv, hd)).astype(np.float32)
+
+    def model_step(params, cfg_, state, toks, kv):
+        return (jnp.zeros((B, cfg_.vocab_size), jnp.float32), state,
+                (jnp.asarray(k_new), jnp.asarray(v_new)))
+
+    monkeypatch.setattr(transformer, "decode_step_paged", model_step)
+    _, _, got = jax.jit(eng._attend_fn)(
+        eng.params, jnp.asarray(pages.reshape(B * L * maxB, pw)),
+        jnp.asarray(lens), jnp.zeros(B, jnp.int32))
+
+    want = pages[np.arange(B)[:, None], np.arange(L), lens[:, None] // bt]
+    kv = want[..., :kvw].view(np.float32).reshape(B, L, 2, bt, hkv, hd)
+    for b in range(B):
+        kv[b, :, 0, lens[b] % bt] = k_new[:, b]
+        kv[b, :, 1, lens[b] % bt] = v_new[:, b]
+    np.testing.assert_array_equal(np.asarray(got), want.reshape(B * L, pw))
 
 
 # ---------------------------------------------------------------------------

@@ -183,3 +183,61 @@ def test_sharded_pool_read_compiles(native, banks, path):
         args = (jax.ShapeDtypeStruct((GATHER,), jnp.int32, sharding=rep),)
         fn = sp.read_any
     _check(jax.jit(fn).lower(pool, *args).compile())
+
+
+def _gathers(hlo: str) -> list[tuple[list[int], int]]:
+    """(slice_sizes, operand elements) of every ``gather`` in HLO text. An
+    operand is named, so its shape is read from the nearest definition
+    above (a fusion's ``parameter``, or the instruction that made it)."""
+    import math
+    import re
+    shapes, out = {}, []
+    for line in hlo.splitlines():
+        d = re.match(r"\s*(?:ROOT )?(%[\w.-]+) = \w+\[([\d,]*)\]", line)
+        if d:
+            shapes[d.group(1)] = [int(x) for x in d.group(2).split(",") if x]
+        g = re.search(r" gather\((%[\w.-]+),.*slice_sizes=\{([\d,]*)\}",
+                      line)
+        if g:
+            out.append(([int(x) for x in g.group(2).split(",")],
+                        math.prod(shapes[g.group(1)])))
+    return out
+
+
+def test_attend_write_back_is_a_row_gather(one_chip, no_persistent_cache,
+                                           monkeypatch):
+    """The decode model step at the benchmark's page geometry (8 slots,
+    64 blocks of 16384-word pages, Qwen3-0.6B widths, 2 of its 28 layers)
+    picks each slot's current block as whole page rows: no gather of single
+    elements over the padded K/V, the form that cost ~82 ms a step."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.serve import Engine
+    from repro.serve import engine as engine_mod
+    real_build = engine_mod.build_model
+
+    def shapes_only(cfg):
+        model = real_build(cfg)
+        return dataclasses.replace(model, init=lambda key: jax.eval_shape(
+            model.init, key))
+
+    monkeypatch.setattr(engine_mod, "build_model", shapes_only)
+    cfg = dataclasses.replace(get_config("qwen3-0.6b"), num_layers=2,
+                              dtype="float32")
+    eng = Engine(cfg, max_batch=8, max_len=512, num_rows=64, row_words=W)
+    B, L, maxB = eng.max_batch, eng.n_layers, eng.kv.max_blocks
+    assert (maxB, eng.kv.page_words) == (64, 8 * W)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype), eng.params)
+    vec = sds((B,), jnp.int32)
+    text = jax.jit(eng._attend_fn).lower(
+        params, sds((B * L * maxB, 8 * W), jnp.uint32), vec, vec) \
+        .compile().as_text()
+    gathers = _gathers(text)
+    assert gathers, "the write-back's row gather is missing"
+    scalar = [g for g in gathers if set(g[0]) == {1} and g[1] > 10**6]
+    assert not scalar, scalar
